@@ -66,7 +66,7 @@ def run(smoke: bool = False, backend: str = "auto", max_devices: int = 16,
     rows = []
     base = None
     for c in _device_counts(max_devices):
-        mesh = jax.sharding.Mesh(np.array(jax.devices()[:c]), ("data",))
+        mesh = shd.make_mesh((c,), ("data",), devices=jax.devices()[:c])
         with shd.use_mesh(mesh):
             pipe.basecall(sig)                       # compile + place
             t0 = time.perf_counter()

@@ -21,7 +21,8 @@ _RULES = {
              "lint-interpret-kwarg", "lint-wrapper-interpret",
              "lint-registry-complete", "lint-parse"),
     "kernels": ("kernel-signature", "kernel-example", "kernel-trace",
-                "kernel-block-div", "kernel-grid", "kernel-vmem"),
+                "kernel-block-div", "kernel-tpu-tiling", "kernel-grid",
+                "kernel-vmem"),
     "trace": ("trace-weight-quant", "trace-dequant", "trace-f64",
               "trace-host-transfer", "trace-stage-coverage",
               "trace-mesh-bake", "trace-retrace"),

@@ -1,6 +1,6 @@
 """Shared jaxpr-walking library: THE one implementation in the repo.
 
-Everything here operates on ``jax.core.ClosedJaxpr`` / ``jax.core.Jaxpr``
+Everything here operates on ``jax.extend.core.ClosedJaxpr`` / ``Jaxpr``
 objects produced by ``jax.make_jaxpr``; nothing executes.  The walkers
 recurse into higher-order primitives (``pjit``, ``scan``, ``cond``,
 ``while``) via the ``ClosedJaxpr`` values found in ``eqn.params``.  Raw
@@ -37,6 +37,7 @@ import re
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Set
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -65,23 +66,23 @@ _STAGE_RE = re.compile(r"stage:([A-Za-z0-9_]+)")
 # structural helpers
 # ---------------------------------------------------------------------------
 
-def as_jaxpr(obj: Any) -> "jax.core.Jaxpr":
+def as_jaxpr(obj: Any) -> "jex_core.Jaxpr":
     """Accept a ClosedJaxpr or Jaxpr and return the raw Jaxpr."""
-    return obj.jaxpr if isinstance(obj, jax.core.ClosedJaxpr) else obj
+    return obj.jaxpr if isinstance(obj, jex_core.ClosedJaxpr) else obj
 
 
-def sub_closed_jaxprs(eqn: Any) -> List["jax.core.ClosedJaxpr"]:
+def sub_closed_jaxprs(eqn: Any) -> List["jex_core.ClosedJaxpr"]:
     """Sub-jaxprs of a higher-order equation (pjit/scan/cond/while...).
 
     Only ``ClosedJaxpr`` params count: ``pallas_call`` stores a raw
     ``Jaxpr`` over refs whose invars do not align with the operands, so
     it is intentionally excluded from dataflow recursion.
     """
-    subs: List[jax.core.ClosedJaxpr] = []
+    subs: List[jex_core.ClosedJaxpr] = []
     for val in eqn.params.values():
         items = val if isinstance(val, (list, tuple)) else (val,)
         for item in items:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jex_core.ClosedJaxpr):
                 subs.append(item)
     return subs
 
@@ -99,7 +100,7 @@ def iter_eqns(jaxpr: Any, *, into_kernels: bool = False) -> Iterator[Any]:
             yield from iter_eqns(sub.jaxpr, into_kernels=into_kernels)
         if into_kernels:
             for val in eqn.params.values():
-                if isinstance(val, jax.core.Jaxpr):
+                if isinstance(val, jex_core.Jaxpr):
                     yield from iter_eqns(val, into_kernels=True)
 
 
@@ -145,7 +146,7 @@ def _var_dtype(v: Any) -> Optional[Any]:
 
 
 def _nonliteral(vs: Sequence[Any]) -> List[Any]:
-    return [v for v in vs if not isinstance(v, jax.core.Literal)]
+    return [v for v in vs if not isinstance(v, jex_core.Literal)]
 
 
 # ---------------------------------------------------------------------------
@@ -163,7 +164,7 @@ def is_quant_eqn(eqn: Any) -> bool:
     return False
 
 
-def _align_sub_taint(eqn: Any, sub: "jax.core.ClosedJaxpr",
+def _align_sub_taint(eqn: Any, sub: "jex_core.ClosedJaxpr",
                      tainted: Set[Any]) -> Set[Any]:
     """Map taint from eqn operands onto sub-jaxpr invars (suffix-aligned)."""
     sub_taint: Set[Any] = set()
@@ -172,12 +173,12 @@ def _align_sub_taint(eqn: Any, sub: "jax.core.ClosedJaxpr",
         j = i + offset
         if 0 <= j < len(eqn.invars):
             ov = eqn.invars[j]
-            if not isinstance(ov, jax.core.Literal) and ov in tainted:
+            if not isinstance(ov, jex_core.Literal) and ov in tainted:
                 sub_taint.add(sv)
     return sub_taint
 
 
-def _outvar_taint(jaxpr: "jax.core.Jaxpr",
+def _outvar_taint(jaxpr: "jex_core.Jaxpr",
                   tainted: Set[Any]) -> List[bool]:
     """One extra linear weight-only pass, then report outvar taint."""
     tainted = set(tainted)
@@ -186,11 +187,11 @@ def _outvar_taint(jaxpr: "jax.core.Jaxpr",
         if invars and all(v in tainted for v in invars):
             for ov in eqn.outvars:
                 tainted.add(ov)
-    return [not isinstance(v, jax.core.Literal) and v in tainted
+    return [not isinstance(v, jex_core.Literal) and v in tainted
             for v in jaxpr.outvars]
 
 
-def collect_weight_quant(jaxpr: "jax.core.Jaxpr",
+def collect_weight_quant(jaxpr: "jex_core.Jaxpr",
                          tainted: Set[Any]) -> List[Any]:
     """Equations doing quantization arithmetic on *weight-only* values.
 
@@ -220,7 +221,7 @@ def collect_weight_quant(jaxpr: "jax.core.Jaxpr",
     return found
 
 
-def weight_quant_eqns(closed: "jax.core.ClosedJaxpr",
+def weight_quant_eqns(closed: "jex_core.ClosedJaxpr",
                       n_param_leaves: int) -> List[Any]:
     """Quantization equations reachable from the first ``n_param_leaves``
     invars of a trace — the flattened parameter pytree when parameters
@@ -234,7 +235,7 @@ def weight_quant_eqns(closed: "jax.core.ClosedJaxpr",
 # code taint: int8/int16 -> float only inside the declared dequant scope
 # ---------------------------------------------------------------------------
 
-def _dequant_walk(jaxpr: "jax.core.Jaxpr", tainted: Set[Any],
+def _dequant_walk(jaxpr: "jex_core.Jaxpr", tainted: Set[Any],
                   scope: str) -> List[Any]:
     found: List[Any] = []
     for v in jaxpr.invars:

@@ -12,6 +12,9 @@ equations:
   kernel-trace       the pallas impl actually lowers to >=1 pallas_call
   kernel-block-div   every BlockSpec block shape divides its (padded)
                      operand shape — the wrapper's padding contract
+  kernel-tpu-tiling  every block's last two dims are divisible by
+                     (8, 128) or equal the operand's own — the rule the
+                     TPU compiler enforces (a 1-D block: 128 or full)
   kernel-grid        no degenerate (zero-sized) grid dimensions
   kernel-vmem        estimated VMEM residency (all operand blocks +
                      scratch) fits the per-core budget
@@ -37,6 +40,10 @@ from repro.analysis.findings import Finding
 #: carry 16 MiB; CPU interpret mode has no real limit but the kernels
 #: must stay deployable).
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
+
+#: (sublane, lane) tile the TPU compiler requires of a block's last two
+#: dims unless they span the operand's whole extent.
+TPU_TILE = (8, 128)
 
 
 def _positional_names(fn: Any) -> List[str]:
@@ -84,8 +91,16 @@ def trace_pallas(entry: Any) -> Any:
 
 
 def _block_dims(block_shape: Sequence[Any]) -> List[int]:
-    """Block extents with Mapped/None dims (size-1 squeezed) as 1."""
-    return [b if isinstance(b, int) else 1 for b in block_shape]
+    """Block extents: ``Blocked(n)`` as n, squeezed dims as 1."""
+    dims = [getattr(b, "block_size", b) for b in block_shape]
+    return [b if isinstance(b, int) else 1 for b in dims]
+
+
+def _tiling_violation(blk: Sequence[int], shape: Sequence[int]) -> bool:
+    """True when a block breaks the TPU's (8, 128)-or-full rule."""
+    n = min(2, len(blk))
+    tail = zip(blk[-n:], shape[-n:], TPU_TILE[-n:])
+    return any(b != dim and b % tile for b, dim, tile in tail)
 
 
 def check_pallas_eqn(eqn: Any, subject: str,
@@ -103,8 +118,8 @@ def check_pallas_eqn(eqn: Any, subject: str,
 
     vmem = 0
     for bi, bm in enumerate(gm.block_mappings):
-        shape = bm.array_shape_dtype.shape
-        dtype = bm.array_shape_dtype.dtype
+        shape = bm.array_aval.shape
+        dtype = bm.array_aval.dtype
         blk = _block_dims(bm.block_shape)
         for d, (dim, b) in enumerate(zip(shape, blk)):
             if b <= 0 or dim % b != 0:
@@ -115,6 +130,14 @@ def check_pallas_eqn(eqn: Any, subject: str,
                     f"({dim} % {b} != 0); pad the operand to a tile "
                     "multiple in the wrapper before pallas_call"))
                 break
+        if blk and _tiling_violation(blk, shape):
+            findings.append(Finding(
+                "kernel-tpu-tiling", subject,
+                f"operand {bi}: block shape {tuple(blk)} over operand "
+                f"{tuple(shape)}: the last two block dims must be "
+                f"divisible by {TPU_TILE} or equal the operand's, or the "
+                "TPU compiler refuses the kernel; block over rows in "
+                "sublane multiples or use the whole dim"))
         vmem += math.prod(blk) * dtype.itemsize
 
     # declared scratch lives in VMEM for the kernel's whole lifetime

@@ -32,6 +32,7 @@ import dataclasses
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from repro.analysis import jaxpr_tools as jt
@@ -42,7 +43,7 @@ from repro.analysis.findings import Finding
 class TraceCase:
     """One serving trace plus its declared intent."""
     name: str
-    closed: "jax.core.ClosedJaxpr"
+    closed: "jex_core.ClosedJaxpr"
     n_param_leaves: int
     boundaries: Tuple[str, ...] = ()
     meshed: bool = False
@@ -60,7 +61,8 @@ def default_mesh():
     devs = jax.devices()
     if len(devs) < 2:
         return None
-    return jax.make_mesh((len(devs),), ("data",))
+    from repro.dist.sharding import make_mesh
+    return make_mesh((len(devs),), ("data",))
 
 
 # ---------------------------------------------------------------------------

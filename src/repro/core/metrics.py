@@ -5,18 +5,23 @@ import numpy as np
 
 
 def edit_distance(a, b) -> int:
-    """Levenshtein distance — the paper's base-calling error count."""
-    a, b = list(a), list(b)
+    """Levenshtein distance — the paper's base-calling error count.
+
+    One numpy pass per symbol of the longer sequence: substitutions and
+    deletions come from the previous row, and the chain of insertions
+    along the row is a running minimum, ``cur[j] = j + min_{k<=j}
+    (cur[k] - k)``."""
+    a, b = np.asarray(list(a)), np.asarray(list(b))
     if len(a) < len(b):
         a, b = b, a
-    prev = list(range(len(b) + 1))
+    j = np.arange(len(b) + 1)
+    prev = j
     for i, ca in enumerate(a, 1):
-        cur = [i]
-        for j, cb in enumerate(b, 1):
-            cur.append(min(prev[j] + 1, cur[j - 1] + 1,
-                           prev[j - 1] + (ca != cb)))
-        prev = cur
-    return prev[-1]
+        cur = np.empty_like(prev)
+        cur[0] = i
+        cur[1:] = np.minimum(prev[1:] + 1, prev[:-1] + (b != ca))
+        prev = np.minimum.accumulate(cur - j) + j
+    return int(prev[-1])
 
 
 def error_rate(pred, pred_len, truth, truth_len) -> float:

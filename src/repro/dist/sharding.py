@@ -40,6 +40,20 @@ def _stack():
     return _state.meshes
 
 
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` — the one mesh factory.
+
+    ``constrain``/``replicate`` annotate values with
+    ``with_sharding_constraint``, which only accepts ``Auto`` axes, while
+    ``jax.make_mesh`` builds ``Explicit`` ones by default.  ``devices``
+    picks the devices (default: all of them).
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         (jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def get_mesh() -> Optional[jax.sharding.Mesh]:
     """The innermost mesh installed by :func:`use_mesh`, or None."""
     stack = _stack()
@@ -69,7 +83,7 @@ def use_mesh(mesh: jax.sharding.Mesh):
 
     Example::
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         with use_mesh(mesh):
             result = pipe.basecall(signal)   # windows shard over "dp"
     """

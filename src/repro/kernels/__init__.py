@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the compute hot-spots Helix optimizes.
 
 Each subpackage ships kernel.py (pl.pallas_call + explicit BlockSpec VMEM
-tiling), ops.py (jit'd wrapper: padding, auto-interpret off-TPU), and
+tiling), ops.py (jit'd wrapper: padding, registry dispatch), and
 ref.py (pure-jnp oracle; tests assert allclose across shape sweeps).
 
   quant_matmul — int8-container low-bit matmul + fused dequant epilogue

@@ -7,9 +7,8 @@ lengths) is everything EXCEPT prefix content — callers replay the
 returned per-frame winner indices to rebuild prefixes (see
 ``core.ctc.ctc_beam_search_hash_batch``'s ``strip_frames`` path).
 
-No padding is needed at this layer: the grid is (B, F) with unit blocks
-on both axes, and the in-kernel candidate row handles its own lane-tile
-padding.
+The kernel wrapper pads the batch to its row tile with inactive rows;
+the candidate axis is never padded.
 """
 from __future__ import annotations
 
@@ -57,7 +56,8 @@ def _example():
 
 
 registry.register_op("beam_merge_multiframe", ref=_impl_ref,
-                     pallas=_impl_pallas, example=_example)
+                     pallas=_impl_pallas, example=_example,
+                     batch_axes=((0,) * 7, (0,) * 6))
 
 
 @functools.partial(jax.jit, static_argnames=("blank", "L", "backend"))

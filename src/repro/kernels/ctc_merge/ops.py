@@ -19,9 +19,8 @@ import jax.numpy as jnp
 
 from repro.kernels import registry
 from repro.kernels.ctc_merge.kernel import (beam_merge_topk_pallas,
-                                            ctc_merge_pallas)
-from repro.kernels.ctc_merge.ref import (MASK, beam_merge_topk_ref,
-                                         ctc_merge_ref)
+                                            ctc_merge_pallas, row_tile)
+from repro.kernels.ctc_merge.ref import beam_merge_topk_ref, ctc_merge_ref
 
 NEG = -1.0e9
 
@@ -57,7 +56,7 @@ def _example():
 
 
 registry.register_op("masked_logsumexp", ref=_impl_ref, pallas=_impl_pallas,
-                     example=_example)
+                     example=_example, batch_axes=((0, 0), 0))
 
 
 @functools.partial(jax.jit, static_argnames=("bi", "backend"))
@@ -80,36 +79,26 @@ def masked_logsumexp(eq: jnp.ndarray, scores: jnp.ndarray, *, bi: int = 128,
 # ---------------------------------------------------------------------------
 
 def _topk_impl_pallas(keys, pb, pnb, *, W: int, interpret: bool = False):
-    """Pad C to the lane tile with inert rank-last lanes, run the fused
-    kernel, trim back to (B, W).
+    """Pad the batch to the row tile, run the fused kernel, trim back.
 
-    Padding invariants (see tests): pad lanes get UNIQUE keys (so each is
-    canonical — a shared sentinel would create non-canonical pad lanes at
-    NEG, which could outrank deeply-dead real candidates) and MASK-level
-    scores, so every real lane strictly outranks every pad lane and the
-    first C output ranks are bitwise what the oracle computes unpadded.
+    Padded rows are independent examples whose outputs are dropped; the
+    candidate axis is never padded (each block spans all C lanes).
     """
     B, C = keys.shape
     keys = jax.lax.bitcast_convert_type(keys.astype(jnp.uint32), jnp.int32) \
         if keys.dtype == jnp.uint32 else keys.astype(jnp.int32)
-    Cp = -(-max(C, W) // 128) * 128
-    if Cp != C:
-        lane = jnp.arange(Cp, dtype=jnp.int32)
-        keys = jnp.concatenate(
-            [keys, jnp.broadcast_to(lane[C:], (B, Cp - C))], axis=1)
-        fill = jnp.full((B, Cp - C), MASK, jnp.float32)
-        pb = jnp.concatenate([pb.astype(jnp.float32), fill], axis=1)
-        pnb = jnp.concatenate([pnb.astype(jnp.float32), fill], axis=1)
+    pad = (-B) % row_tile(B)
+    rows = ((0, pad), (0, 0))
     idx, opb, opnb = beam_merge_topk_pallas(
-        keys, pb.astype(jnp.float32), pnb.astype(jnp.float32),
-        interpret=interpret)
-    idx, opb, opnb = idx[:, :W], opb[:, :W], opnb[:, :W]
-    if W > C:   # ranks >= C are padding by construction
+        jnp.pad(keys, rows), jnp.pad(pb.astype(jnp.float32), rows),
+        jnp.pad(pnb.astype(jnp.float32), rows), W=W, interpret=interpret)
+    idx, opb, opnb = idx[:B], opb[:B], opnb[:B]
+    if W > C:   # ranks >= C hold no candidate
         is_pad = jnp.arange(W) >= C
         idx = jnp.where(is_pad[None], C - 1, idx)
         opb = jnp.where(is_pad[None], NEG, opb)
         opnb = jnp.where(is_pad[None], NEG, opnb)
-    return jnp.clip(idx, 0, C - 1), opb, opnb
+    return idx, opb, opnb
 
 
 def _topk_impl_ref(keys, pb, pnb, *, W: int, **_tiles):
@@ -121,7 +110,7 @@ def _topk_impl_ref(keys, pb, pnb, *, W: int, **_tiles):
 
 
 def _topk_example():
-    """Ragged candidate count vs the 128 lane tile."""
+    """Ragged batch vs the 8-row tile, ragged candidate count."""
     B, C = 2, 45
     keys = jnp.arange(B * C, dtype=jnp.int32).reshape(B, C) % 12
     return ((keys, jnp.zeros((B, C), jnp.float32),
@@ -129,7 +118,8 @@ def _topk_example():
 
 
 registry.register_op("beam_merge_topk", ref=_topk_impl_ref,
-                     pallas=_topk_impl_pallas, example=_topk_example)
+                     pallas=_topk_impl_pallas, example=_topk_example,
+                     batch_axes=((0, 0, 0), (0, 0, 0)))
 
 
 @functools.partial(jax.jit, static_argnames=("W", "backend"))

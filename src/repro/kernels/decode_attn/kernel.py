@@ -18,9 +18,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 M_INIT = -0.5e9
 MASK_NEG = -1.0e9
@@ -28,6 +27,7 @@ MASK_NEG = -1.0e9
 
 def _decode_kernel(nv_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                    acc_ref, *, bl: int, kv_heads: int, groups: int):
+    b = pl.program_id(0)
     li = pl.program_id(1)
 
     @pl.when(li == 0)
@@ -44,7 +44,7 @@ def _decode_kernel(nv_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     s = jnp.einsum("hgd,lhd->hgl", qh, k)              # (Kv, G, bl)
     pos = li * bl + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bl), 2)
-    s = jnp.where(pos < nv_ref[0, 0], s, MASK_NEG)
+    s = jnp.where(pos < nv_ref[b, 0], s, MASK_NEG)
     s = s.reshape(kv_heads * groups, bl)
 
     m_old = m_ref[...]                                 # (Kv*G, 1)
@@ -149,7 +149,7 @@ def paged_decode_attn_pallas(q: jnp.ndarray, k_arena: jnp.ndarray,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, n_valid, q, k_arena, v_arena)
@@ -170,22 +170,26 @@ def decode_attn_pallas(q: jnp.ndarray, k_cache: jnp.ndarray,
     import functools
     kern = functools.partial(_decode_kernel, bl=bl, kv_heads=Kv,
                              groups=groups)
-    grid = (B, L // bl)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
+    # n_valid rides as a scalar-prefetch (SMEM) operand: a (1, 1) VMEM
+    # block of a (B, 1) array breaks the TPU's (8, 128)-or-full rule
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, L // bl),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, l: (b, 0)),
-            pl.BlockSpec((1, H, D), lambda b, l: (b, 0, 0)),
-            pl.BlockSpec((1, bl, Kv, D), lambda b, l: (b, l, 0, 0)),
-            pl.BlockSpec((1, bl, Kv, D), lambda b, l: (b, l, 0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, l, nv: (b, 0, 0)),
+            pl.BlockSpec((1, bl, Kv, D), lambda b, l, nv: (b, l, 0, 0)),
+            pl.BlockSpec((1, bl, Kv, D), lambda b, l, nv: (b, l, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, l: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, l, nv: (b, 0, 0)),
         scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32),
                         pltpu.VMEM((H, D), jnp.float32)],
-        compiler_params=CompilerParams(
+    )
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(n_valid, q, k_cache, v_cache)

@@ -22,23 +22,29 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+# f32 at full precision on the MXU, here and in the oracle
+# (gru_cell.ref): a default-precision f32 dot on a TPU rounds its
+# inputs to bf16, and the kernel and its oracle must agree on the chip
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _gru_kernel(xp_ref, h_ref, u_ref, b_ref, o_ref):
+def _gru_kernel(xp_ref, h_ref, u_ref, b_ref, bn_ref, o_ref):
     h = h_ref[...]                      # (bb, H)
     u = u_ref[...]                      # (H, 3H)
     xp = xp_ref[...]                    # (bb, 3H)
     b = b_ref[...]                      # (1, 3H)
     H = h.shape[-1]
+    # n-gate bias is its own (1, H) block (see gru_seq.kernel)
 
-    gates = jnp.dot(h, u, preferred_element_type=jnp.float32) + xp + b
+    gates = jnp.dot(h, u, precision=HIGHEST,
+                    preferred_element_type=jnp.float32) + xp + b
     z = jax.nn.sigmoid(gates[:, :H])
     r = jax.nn.sigmoid(gates[:, H:2 * H])
-    n_in = xp[:, 2 * H:] + b[:, 2 * H:]
-    n_h = jnp.dot(r * h, u[:, 2 * H:], preferred_element_type=jnp.float32)
+    n_in = xp[:, 2 * H:] + bn_ref[...]
+    n_h = jnp.dot(r * h, u[:, 2 * H:], precision=HIGHEST,
+                  preferred_element_type=jnp.float32)
     n = jnp.tanh(n_in + n_h)
     o_ref[...] = z * h + (1.0 - z) * n
 
@@ -60,10 +66,11 @@ def gru_cell_pallas(x_proj: jnp.ndarray, h: jnp.ndarray, u: jnp.ndarray,
             pl.BlockSpec((bb, H), lambda i: (i, 0)),
             pl.BlockSpec((H, 3 * H), lambda i: (0, 0)),   # stationary
             pl.BlockSpec((1, 3 * H), lambda i: (0, 0)),
+            pl.BlockSpec((1, H), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bb, H), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x_proj, h, u, b)
+    )(x_proj, h, u, b, b[:, 2 * H:])

@@ -38,7 +38,7 @@ def _example():
 
 
 registry.register_op("gru_cell", ref=_impl_ref, pallas=_impl_pallas,
-                     example=_example)
+                     example=_example, batch_axes=((0, 0, None, None), 0))
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "backend"))

@@ -35,10 +35,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+HIGHEST = jax.lax.Precision.HIGHEST    # as in gru_cell.kernel, which says why
 
 
-def _gru_seq_kernel(xp_ref, h0_ref, u_ref, b_ref, o_ref, h_scratch):
+def _gru_seq_kernel(xp_ref, h0_ref, u_ref, b_ref, bn_ref, o_ref, h_scratch):
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -50,12 +50,16 @@ def _gru_seq_kernel(xp_ref, h0_ref, u_ref, b_ref, o_ref, h_scratch):
     xp = xp_ref[0]                      # (bb, 3H) — this step's tile
     b = b_ref[...]                      # (1, 3H)
     H = h.shape[-1]
+    # the n-gate bias arrives as its own (1, H) block: a lane-offset slice
+    # b[:, 2H:] broadcast to (bb, H) has a layout Mosaic refuses
 
-    gates = jnp.dot(h, u, preferred_element_type=jnp.float32) + xp + b
+    gates = jnp.dot(h, u, precision=HIGHEST,
+                    preferred_element_type=jnp.float32) + xp + b
     z = jax.nn.sigmoid(gates[:, :H])
     r = jax.nn.sigmoid(gates[:, H:2 * H])
-    n_in = xp[:, 2 * H:] + b[:, 2 * H:]
-    n_h = jnp.dot(r * h, u[:, 2 * H:], preferred_element_type=jnp.float32)
+    n_in = xp[:, 2 * H:] + bn_ref[...]
+    n_h = jnp.dot(r * h, u[:, 2 * H:], precision=HIGHEST,
+                  preferred_element_type=jnp.float32)
     n = jnp.tanh(n_in + n_h)
     hn = z * h + (1.0 - z) * n
     h_scratch[...] = hn
@@ -80,11 +84,12 @@ def gru_seq_pallas(x_proj: jnp.ndarray, h0: jnp.ndarray, u: jnp.ndarray,
             pl.BlockSpec((bb, H), lambda i, t: (i, 0)),
             pl.BlockSpec((H, 3 * H), lambda i, t: (0, 0)),   # stationary
             pl.BlockSpec((1, 3 * H), lambda i, t: (0, 0)),
+            pl.BlockSpec((1, H), lambda i, t: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bb, H), lambda i, t: (t, i, 0)),
         out_shape=jax.ShapeDtypeStruct((T, B, H), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, H), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x_proj, h0, u, b)
+    )(x_proj, h0, u, b, b[:, 2 * H:])

@@ -45,7 +45,7 @@ def _example():
 
 
 registry.register_op("gru_seq", ref=_impl_ref, pallas=_impl_pallas,
-                     example=_example)
+                     example=_example, batch_axes=((1, 0, None, None), 1))
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "backend"))

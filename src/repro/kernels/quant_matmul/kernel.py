@@ -20,9 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _qmm_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref):
@@ -68,7 +66,7 @@ def quant_matmul_pallas(xq: jnp.ndarray, wq: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xq, wq, x_scale, w_scale)

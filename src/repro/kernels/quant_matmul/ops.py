@@ -52,7 +52,8 @@ def _example():
 
 
 registry.register_op("quant_matmul", ref=_impl_ref, pallas=_impl_pallas,
-                     example=_example)
+                     example=_example,
+                     batch_axes=((0, None, None, None), 0))
 
 
 @functools.partial(jax.jit,
@@ -99,7 +100,10 @@ def qmm_packed(x: jnp.ndarray, wq: jnp.ndarray, sw: jnp.ndarray,
     lead, F = x.shape[:-1], x.shape[-1]
     xq, sx = quant_lib.pack_act_rows(x.reshape(-1, F), bits_a)
     one = jnp.ones((1, 1), jnp.float32)
-    y = quant_matmul(xq, wq, one, sw.reshape(1, -1), backend=backend) * sx
+    # resolved here, inside the caller's trace, so the kernel maps over
+    # the ambient mesh's dp shards (see kernels.registry)
+    qmm = registry.get_op("quant_matmul", backend)
+    y = qmm(xq, wq, one, sw.reshape(1, -1)) * sx
     return y.reshape(lead + (wq.shape[-1],))
 
 

@@ -13,9 +13,10 @@ and callers resolve a concrete callable with::
     op = registry.get_op("quant_matmul", backend="auto")
 
 Backends:
-  auto       pallas on TPU, interpret elsewhere (the old per-op
-             ``_auto_interpret`` heuristic, now in exactly one place)
-  pallas     compiled Pallas kernel (TPU)
+  auto       pallas on TPU, interpret on the CPU test platform; any other
+             platform is an error, never a silent fallback
+  pallas     compiled Pallas kernel (TPU only: off-TPU it raises rather
+             than run the kernel in interpret mode)
   interpret  Pallas kernel body on the interpreter (CPU-testable)
   ref        the jnp oracle
 
@@ -26,6 +27,13 @@ process-wide (benchmarks ``--backend``, CI).
 
 Ops register themselves at import of their ``ops.py``; ``get_op`` lazily
 imports the owning module so callers never need kernel-package imports.
+
+Under an ambient ``dist.sharding.use_mesh`` mesh, an op that declares its
+``batch_axes`` runs its Pallas kernel once per data-parallel shard
+(``shard_map`` over the logical "dp" axis): XLA cannot partition a Mosaic
+kernel itself, and every registered row is independent of the others, so
+mapping the kernel over shards is the same computation.  The choice is
+made when the op is called, under the mesh ambient at that trace.
 """
 from __future__ import annotations
 
@@ -34,9 +42,10 @@ import difflib
 import functools
 import importlib
 import os
-from typing import Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 BACKENDS = ("auto", "pallas", "interpret", "ref")
 
@@ -63,6 +72,10 @@ class OpEntry:
     # on representative (deliberately ragged) shapes; used by
     # ``repro.analysis.kernel_checks`` to trace the kernel statically
     example: Optional[Callable] = None
+    # (per positional arg, per output) the axis along which rows are
+    # independent, None for an argument every row shares; set, it lets
+    # the Pallas kernel run per dp shard under a mesh (see module doc)
+    batch_axes: Optional[Tuple[Tuple, Any]] = None
 
 
 _REGISTRY: Dict[str, OpEntry] = {}
@@ -75,7 +88,8 @@ _default_backend: Optional[str] = None
 
 
 def register_op(name: str, *, ref: Callable, pallas: Callable,
-                example: Optional[Callable] = None) -> None:
+                example: Optional[Callable] = None,
+                batch_axes: Optional[Tuple[Tuple, Any]] = None) -> None:
     """Register (or re-register) an op's reference + Pallas implementations.
 
     Called at import time by each kernel package's ``ops.py`` (see
@@ -91,6 +105,11 @@ def register_op(name: str, *, ref: Callable, pallas: Callable,
         example: zero-argument factory returning ``(args, kwargs)`` on
             representative shapes — lets ``repro.analysis`` (and other
             tooling) trace the op without knowing its signature.
+        batch_axes: ``(in_axes, out_axes)`` — for each positional
+            argument, and for each output (same tree as the result), the
+            axis along which rows are independent, or None for an
+            argument every row shares.  Declared, the Pallas kernel runs
+            per data-parallel shard under an ambient mesh.
 
     Returns:
         None.
@@ -101,10 +120,12 @@ def register_op(name: str, *, ref: Callable, pallas: Callable,
                     example=lambda: ((jnp.zeros((3, 5)),), {}))
     """
     prev = _REGISTRY.get(name)
-    if example is None and prev is not None:
-        example = prev.example   # re-registration (tests) keeps the example
+    if prev is not None:   # re-registration (tests) keeps the declarations
+        example = example if example is not None else prev.example
+        batch_axes = batch_axes if batch_axes is not None \
+            else prev.batch_axes
     _REGISTRY[name] = OpEntry(name=name, ref=ref, pallas=pallas,
-                              example=example)
+                              example=example, batch_axes=batch_axes)
 
 
 def list_ops() -> tuple:
@@ -141,15 +162,37 @@ def get_default_backend() -> str:
     return _default_backend
 
 
+def _platform() -> str:
+    return jax.default_backend()
+
+
 def resolve_backend(backend: Optional[str] = None) -> str:
-    """None/"auto" -> the concrete backend for this process/host."""
+    """None/"auto" -> the concrete backend for this process/host.
+
+    "auto" is "pallas" on a TPU and "interpret" on the CPU test platform.
+    Asking for "pallas" off-TPU — or "auto" on any other platform —
+    raises instead of quietly running the kernel bodies in interpret
+    mode, so a run never reports interpreter numbers as the device's."""
     b = backend or get_default_backend()
     if b == "auto":
         b = get_default_backend()
+    platform = _platform()
     if b == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "interpret"
+        if platform == "tpu":
+            return "pallas"
+        if platform == "cpu":
+            return "interpret"
+        raise RuntimeError(
+            f"backend 'auto' has no kernel path on platform {platform!r}: "
+            "Pallas kernels compile for TPU only; pass backend='ref' (or "
+            "'interpret' to debug the kernel bodies)")
     if b not in BACKENDS:
         raise ValueError(f"unknown backend {b!r}; one of {BACKENDS}")
+    if b == "pallas" and platform != "tpu":
+        raise RuntimeError(
+            f"backend 'pallas' needs a TPU but JAX runs on {platform!r}; "
+            "use backend='interpret' to run the kernel bodies on the CPU "
+            "or 'ref' for the jnp oracles")
     return b
 
 
@@ -191,7 +234,42 @@ def get_op(name: str, backend: Optional[str] = None) -> Callable:
     b = resolve_backend(backend)
     if b == "ref":
         return entry.ref
-    return functools.partial(entry.pallas, interpret=(b == "interpret"))
+    kernel = functools.partial(entry.pallas, interpret=(b == "interpret"))
+    if entry.batch_axes is None:
+        return kernel
+    return functools.partial(_per_dp_shard, kernel, *entry.batch_axes)
+
+
+def _per_dp_shard(kernel: Callable, in_axes: Tuple, out_axes: Any,
+                  *args, **kwargs):
+    """``kernel(*args, **kwargs)``, run once per data-parallel shard when
+    an ambient mesh has a "dp" axis (see the module docstring)."""
+    from repro.dist import sharding as shd
+
+    mesh = shd.get_mesh()
+    dp = None if mesh is None else shd.logical_spec(("dp",), mesh)[0]
+    if dp is None:
+        return kernel(*args, **kwargs)
+    n = shd.dp_size(mesh)
+    for a, ax in zip(args, in_axes):
+        if ax is not None and a.shape[ax] % n:
+            raise ValueError(
+                f"batch dim {a.shape[ax]} (axis {ax} of a {a.shape} "
+                f"operand) does not divide the {n} data-parallel devices")
+
+    def spec(ax, ndim):
+        return P(*(dp if d == ax else None for d in range(ndim)))
+
+    fn = functools.partial(kernel, **kwargs)
+    out = jax.eval_shape(fn, *args)
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(P() if ax is None else spec(ax, a.ndim)
+                       for a, ax in zip(args, in_axes)),
+        out_specs=jax.tree_util.tree_map(lambda ax, o: spec(ax, o.ndim),
+                                         out_axes, out),
+        axis_names=set(dp if isinstance(dp, tuple) else (dp,)),
+        check_vma=False)(*args)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -23,9 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _cmp_kernel(a_ref, b_ref, ra_ref, rb_ref, o_ref, acc_ref):
@@ -69,7 +67,7 @@ def vote_cmp_pallas(a_bits: jnp.ndarray, bT_bits: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((N1, N2), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_bits, bT_bits, rowsum_a, rowsum_b)

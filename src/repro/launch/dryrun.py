@@ -57,8 +57,6 @@ def run_cell(arch: str, shape_id: str, mesh_name: str,
             t_compile = time.monotonic() - t0 - t_lower
 
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # older jax: list of one dict
-                cost = cost[0]
             mem = compiled.memory_analysis()
             hlo = compiled.as_text()
         colls = analysis.collective_stats(hlo, n_dev)

@@ -14,23 +14,17 @@ from __future__ import annotations
 
 import jax
 
-
-def _axis_kw(n_axes: int) -> dict:
-    """axis_types only exists on newer jax; omit it elsewhere (same default)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+from repro.dist.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Degenerate mesh over the locally available devices (tests/examples)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"),
-                         **_axis_kw(2))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import functools
 import importlib.util
+import os
 import random
 import sys
 import types
@@ -27,6 +28,12 @@ import types
 from repro.hostdev import force_host_devices  # noqa: E402
 
 force_host_devices(4)
+
+# The tests, and every child process they start (children copy this
+# environment), run on the CPU: a TPU belongs to one process at a time,
+# so a test run on a machine with a chip must never take it.  The chip is
+# driven by ``chip_smoke.py``, alone.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def _install_hypothesis_fallback() -> None:
@@ -117,10 +124,11 @@ def host_mesh4():
     Skips when the process has fewer than 4 devices — e.g. when something
     imported jax before this conftest's XLA_FLAGS append could take."""
     import jax
+    from repro.dist.sharding import make_mesh
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 host devices "
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=4)")
-    return jax.make_mesh((4,), ("data",))
+    return make_mesh((4,), ("data",))
 
 
 @pytest.fixture(scope="session")

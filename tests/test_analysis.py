@@ -61,7 +61,8 @@ print(json.dumps(out))
 def probe():
     r = subprocess.run([sys.executable, "-c", PROBE], capture_output=True,
                        text=True, env={**__import__("os").environ,
-                                        "PYTHONPATH": "src"},
+                                        "PYTHONPATH": "src",
+                                        "JAX_PLATFORMS": "cpu"},
                        timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
     return json.loads(r.stdout.strip().splitlines()[-1])

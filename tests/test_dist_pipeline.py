@@ -199,7 +199,7 @@ def test_place_params_caches_by_mesh_value(tiny_pipe, host_mesh4):
     stays a hit even if that implementation detail changes.)"""
     packed = tiny_pipe.serving_params()
     placed1 = tiny_pipe._place_params(packed, host_mesh4)
-    clone = jax.make_mesh((4,), ("data",))
+    clone = shd.make_mesh((4,), ("data",))
     assert clone == host_mesh4
     placed2 = tiny_pipe._place_params(packed, clone)
     assert placed2 is placed1

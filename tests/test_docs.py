@@ -55,7 +55,7 @@ def test_doc_snippets_execute(path):
     env = dict(os.environ)
     env["PYTHONPATH"] = (str(REPO / "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
-    env["JAX_PLATFORM_NAME"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     # override any inherited device-count flag: the subprocess is
     # deliberately isolated and the sharding guide expects 4 devices
     force_host_devices(4, env, override=True)

@@ -21,10 +21,7 @@ from repro.train import checkpoint as ckpt_lib
 from repro.dist import sharding as shd
 
 def mesh(shape):
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):   # absent on older jax
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-    return jax.make_mesh(shape, ("data", "model"), **kw)
+    return shd.make_mesh(shape, ("data", "model"))
 
 tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
         "b": jnp.linspace(0, 1, 8)}
@@ -52,7 +49,8 @@ print(json.dumps(out))
 
 def test_checkpoint_elastic_across_meshes():
     r = subprocess.run([sys.executable, "-c", PROBE], capture_output=True,
-                       text=True, env={**os.environ, "PYTHONPATH": "src"},
+                       text=True, env={**os.environ, "PYTHONPATH": "src",
+                                        "JAX_PLATFORMS": "cpu"},
                        timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])

@@ -141,8 +141,81 @@ def test_unknown_backend_rejected():
 
 
 def test_backend_auto_resolves_off_tpu_to_interpret():
-    assert registry.Backend("auto").resolved in ("interpret", "pallas")
+    """On the CPU test platform "auto" runs the kernel bodies in
+    interpret mode."""
+    assert jax.default_backend() == "cpu"
+    assert registry.Backend("auto").resolved == "interpret"
+    assert registry.resolve_backend(None) == "interpret"
     assert registry.resolve_backend("ref") == "ref"
+
+
+def test_pallas_backend_off_tpu_raises_without_interpreting(monkeypatch):
+    """backend="pallas" on the CPU is an error naming the TPU, raised
+    before any kernel runs — never a quiet fall back to interpret mode."""
+    from repro.kernels.gru_cell import ops as gru_ops
+
+    entry = registry._REGISTRY["gru_cell"] if "gru_cell" in \
+        registry._REGISTRY else registry._ensure("gru_cell")
+    calls = []
+
+    def spy_pallas(*a, interpret=False, **kw):
+        calls.append(interpret)
+        return entry.pallas(*a, interpret=interpret, **kw)
+
+    monkeypatch.setitem(registry._REGISTRY, "gru_cell",
+                        registry.OpEntry("gru_cell", entry.ref, spy_pallas,
+                                         entry.example))
+    (xp, h, u, b), _ = _gru_cell_args(np.random.default_rng(3))
+    for resolve in (lambda: registry.resolve_backend("pallas"),
+                    lambda: registry.Backend("pallas").resolved,
+                    lambda: registry.get_op("gru_cell", "pallas"),
+                    lambda: gru_ops.gru_cell(xp, h, u, b, backend="pallas")):
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            resolve()
+    assert calls == []
+    # the process default cannot smuggle it in either
+    monkeypatch.setattr(registry, "_default_backend", "pallas")
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        registry.resolve_backend(None)
+
+
+def test_kernel_runs_per_dp_shard_under_mesh(host_mesh4):
+    """Under a dp mesh a kernel with declared batch axes runs inside a
+    shard_map (XLA cannot partition a Mosaic kernel), bitwise equal to the
+    unsharded call; a batch the devices do not divide is refused."""
+    from repro.dist import sharding as shd
+
+    rng = np.random.default_rng(5)
+    T, B, H = 3, 8, 16
+    xp = jnp.asarray(rng.standard_normal((T, B, 3 * H)), jnp.float32)
+    h0 = jnp.asarray(rng.standard_normal((B, H)), jnp.float32)
+    u = jnp.asarray(rng.standard_normal((H, 3 * H)) * 0.3, jnp.float32)
+    b = jnp.asarray(rng.standard_normal((3 * H,)) * 0.1, jnp.float32)
+    op = registry.get_op("gru_seq", "interpret")
+
+    def trace():   # a fresh function: jit caches ignore the ambient mesh
+        return str(jax.make_jaxpr(lambda *a: op(*a))(xp, h0, u, b))
+
+    want = op(xp, h0, u, b)
+    assert "shard_map" not in trace()
+    with shd.use_mesh(host_mesh4):
+        assert "shard_map" in trace()
+        got = jax.jit(lambda *a: op(*a))(xp, h0, u, b)
+        with pytest.raises(ValueError, match="does not divide"):
+            op(xp[:, :6], h0[:6], u, b)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_auto_backend_refuses_platforms_other_than_cpu_and_tpu(monkeypatch):
+    """interpret is the CPU test platform's mapping only; on a TPU "auto"
+    and "pallas" both mean the compiled kernels."""
+    monkeypatch.setattr(registry, "_platform", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no kernel path"):
+        registry.resolve_backend("auto")
+    assert registry.resolve_backend("interpret") == "interpret"
+    monkeypatch.setattr(registry, "_platform", lambda: "tpu")
+    assert registry.resolve_backend("auto") == "pallas"
+    assert registry.resolve_backend("pallas") == "pallas"
 
 
 def test_default_backend_rebinding():

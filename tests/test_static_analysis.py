@@ -43,7 +43,7 @@ QUANT = QuantConfig(enabled=True, bits_w=5, bits_a=5)
 
 
 def _env(**extra):
-    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORM_NAME": "cpu"}
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
     env.update(extra)
     return env
 
@@ -223,6 +223,36 @@ def test_block_divisibility_rule_fires():
           if f.rule == "kernel-block-div"]
     assert fs and "10 % 3" in fs[0].message
     assert "pad the operand" in fs[0].message          # actionable fix
+
+
+def test_tpu_tiling_rule_fires_on_unit_row_strip_block():
+    """Mutant: the old beam-strip layout — a (1, 1, A) log-prob block over
+    (B, F, A) and (1, W) state blocks over (B, W).  Every block divides
+    its operand, yet the TPU compiler refuses it: the last two dims are
+    neither (8, 128)-aligned nor the operand's own."""
+    from jax.experimental import pallas as pl
+
+    B, F, A, W = 4, 8, 5, 5
+
+    def k(lp_ref, st_ref, o_ref):
+        o_ref[...] = st_ref[...] + lp_ref[0][:, :W]
+
+    def old_strip(lp, st):
+        return pl.pallas_call(
+            k, out_shape=jax.ShapeDtypeStruct((B, W), jnp.float32),
+            grid=(B, F),
+            in_specs=[pl.BlockSpec((1, 1, A), lambda b, f: (b, f, 0)),
+                      pl.BlockSpec((1, W), lambda b, f: (b, 0))],
+            out_specs=pl.BlockSpec((1, W), lambda b, f: (b, 0)),
+            interpret=True)(lp, st)
+
+    closed = jax.make_jaxpr(old_strip)(jnp.zeros((B, F, A), jnp.float32),
+                                       jnp.zeros((B, W), jnp.float32))
+    fs = kc.check_pallas_eqn(kc.pallas_call_eqns(closed)[0], "mutant")
+    assert not [f for f in fs if f.rule == "kernel-block-div"]
+    tiling = [f for f in fs if f.rule == "kernel-tpu-tiling"]
+    assert len(tiling) == 3                    # lp, state in, state out
+    assert "(1, 1, 5)" in tiling[0].message and "(8, 128)" in tiling[0].message
 
 
 def test_vmem_budget_rule_fires():

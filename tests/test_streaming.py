@@ -166,7 +166,7 @@ def _assert_result_equal(got, want, msg=""):
                                   err_msg=msg)
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(n=st.integers(min_value=0, max_value=300),
        seed=st.integers(min_value=0, max_value=1_000))
 def test_session_chunk_size_invariance(n, seed):

@@ -120,7 +120,8 @@ def test_checkpoint_elastic_resharding(tmp_path):
     # "new cluster": single-device sharding spec (degenerate but exercises
     # the device_put path with an explicit Sharding object)
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.dist.sharding import make_mesh
+    mesh = make_mesh((1,), ("data",))
     sh = NamedSharding(mesh, P("data", None))
     out, _ = ckpt_lib.restore(str(tmp_path), tree, sharding_tree=sh)
     assert out["w"].sharding == sh
