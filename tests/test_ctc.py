@@ -222,15 +222,19 @@ def test_beam_search_paper_example():
 @settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 10_000))
 def test_beam_search_monotone_in_width(seed):
-    """Best score never decreases as beam widens (property)."""
+    """At every beam width the best score is bounded by the exact
+    posterior: it never exceeds the probability of its own read, which
+    never exceeds the MAP read's.  (The best score itself is NOT monotone
+    in width: pruning at a wider beam can keep a different, worse read —
+    seed 3550 is one such case.)"""
     rng = np.random.default_rng(seed)
     lp = _rand_logprobs(rng, 6, 4)
-    best = -np.inf
+    exact = dict(all_decodes_ranked(np.asarray(lp), blank=3))
+    best = max(exact.values())
     for W in (1, 2, 4, 8):
-        _, _, scores = ctc_lib.ctc_beam_search(lp, beam_width=W)
-        s = float(scores[0])
-        assert s >= best - 1e-5
-        best = max(best, s)
+        prefixes, lens, scores = ctc_lib.ctc_beam_search(lp, beam_width=W)
+        read = tuple(np.asarray(prefixes[0][: int(lens[0])]))
+        assert float(scores[0]) <= exact[read] + 1e-5 <= best + 1e-5
 
 
 def test_beam_search_batch_shapes():

@@ -12,6 +12,7 @@ Achieved values at pin time (jax CPU, seed-fixed):
   consensus identity    0.467
 """
 import numpy as np
+import pytest
 
 from repro.core import metrics
 
@@ -21,6 +22,31 @@ CONSENSUS_IDENTITY_FLOOR = 0.35
 
 def _identity(read, length, truth) -> float:
     return 1.0 - metrics.edit_distance(read[: int(length)], truth) / len(truth)
+
+
+def _edit_distance_loop(a, b) -> int:
+    """The textbook row-by-row Levenshtein recurrence, kept as the
+    reference for the vectorized ``metrics.edit_distance``."""
+    prev = list(range(len(b) + 1))
+    for i, ca in enumerate(a, 1):
+        cur = [i]
+        for j, cb in enumerate(b, 1):
+            cur.append(min(prev[j] + 1, cur[j - 1] + 1,
+                           prev[j - 1] + (ca != cb)))
+        prev = cur
+    return prev[-1]
+
+
+@pytest.mark.parametrize("n1,n2", [(0, 0), (0, 7), (9, 0), (1, 1), (13, 40),
+                                   (64, 57), (120, 120)])
+def test_edit_distance_matches_loop_reference(n1, n2):
+    rng = np.random.default_rng(n1 * 1000 + n2)
+    for _ in range(5):
+        a = rng.integers(0, 4, n1)
+        b = a[:n2].copy() if n2 <= n1 else rng.integers(0, 4, n2)
+        b[rng.random(len(b)) < 0.3] = rng.integers(0, 4)     # mutate some
+        assert metrics.edit_distance(a, b) == _edit_distance_loop(a, b)
+        assert metrics.edit_distance(b, a) == _edit_distance_loop(b, a)
 
 
 def test_golden_window_read_accuracy(golden_pipeline):

@@ -154,8 +154,7 @@ def test_pallas_backend_off_tpu_raises_without_interpreting(monkeypatch):
     before any kernel runs — never a quiet fall back to interpret mode."""
     from repro.kernels.gru_cell import ops as gru_ops
 
-    entry = registry._REGISTRY["gru_cell"] if "gru_cell" in \
-        registry._REGISTRY else registry._ensure("gru_cell")
+    entry = registry._REGISTRY["gru_cell"]     # registered by the import
     calls = []
 
     def spy_pallas(*a, interpret=False, **kw):
