@@ -493,8 +493,9 @@ def _hash_beam_strips(log_probs, logit_lengths, mode, *, W: int, blank: int,
             lp_strip, act_strip, keys, p_b, p_nb, last, lengths,
             blank=blank, L=L)
         # lengths from the replay are provably the kernel's ``_lens``
-        (prefixes, lengths), _ = jax.lax.scan(
-            replay, (prefixes, lengths), jnp.moveaxis(idx, 1, 0))
+        with jax.named_scope("ctc_replay"):
+            (prefixes, lengths), _ = jax.lax.scan(
+                replay, (prefixes, lengths), jnp.moveaxis(idx, 1, 0))
         return (prefixes, lengths, keys, last, p_b, p_nb), None
 
     xs = (jnp.moveaxis(lps.reshape(B, S, F, A), 1, 0),

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import ctc as ctc_lib
 from repro.core import seat as seat_lib
 from repro.core.quant import QuantConfig
@@ -109,6 +110,7 @@ class BasecallResult:
                    window_lengths=np.zeros((0,), np.int32))
 
     @classmethod
+    @telemetry.span("vote")
     def from_window_reads(cls, reads: np.ndarray, lengths: np.ndarray,
                           *, max_read_len: int,
                           span: Optional[int] = None) -> "BasecallResult":
@@ -382,7 +384,7 @@ class BasecallPipeline:
         strip = self.decode_strip
 
         @jax.jit
-        def fn(params, windows, logit_lengths):
+        def decode_windows(params, windows, logit_lengths):
             # under an ambient mesh the window batch stays split over the
             # logical "dp" axis through model + decode; the final replicate
             # is the all-gather that hands the host the full window set
@@ -423,7 +425,7 @@ class BasecallPipeline:
                 scores = shd.replicate(scores)
             return reads, lens, scores
 
-        return fn
+        return decode_windows
 
     @functools.cached_property
     def _windows_fused(self):

@@ -42,6 +42,7 @@ from typing import (Any, Callable, Dict, Iterator, List, Optional, Protocol,
 
 import numpy as np
 
+from repro import telemetry
 from repro.serve.scheduler import SlotScheduler
 
 BACKPRESSURE_POLICIES = ("reject", "block", "shed-oldest")
@@ -179,6 +180,10 @@ class ServerMetrics:
     #: distinct from the full-request latency percentiles above
     ttfe_p50_s: float = 0.0
     ttfe_p99_s: float = 0.0
+    #: submit -> admitted to a lane (queue wait) tails, on the server's
+    #: clock; one sample per request, at its first admission
+    queue_wait_p50_s: float = 0.0
+    queue_wait_p99_s: float = 0.0
     #: per hosted-model metric slices, keyed by model id (empty for
     #: engines/requests without ``model=`` routing)
     per_model: Dict[str, ModelMetrics] = dataclasses.field(
@@ -202,6 +207,10 @@ class ServerMetrics:
             (f"{prefix}/ttfe_p50_s", f"{self.ttfe_p50_s:.4f}",
              f"ejected={self.ejected}"),
             (f"{prefix}/ttfe_p99_s", f"{self.ttfe_p99_s:.4f}", ""),
+            (f"{prefix}/queue_wait_p50_s", f"{self.queue_wait_p50_s:.4f}",
+             "submit -> admitted to a lane"),
+            (f"{prefix}/queue_wait_p99_s", f"{self.queue_wait_p99_s:.4f}",
+             ""),
         ]
         for mid in sorted(self.per_model):
             m = self.per_model[mid]
@@ -333,6 +342,7 @@ class _Record:
     submitted_at: float
     expires_at: Optional[float]
     model: Optional[str] = None       # per-model metrics key (or None)
+    admitted: bool = False            # queue wait recorded
     events: List[ServeEvent] = dataclasses.field(default_factory=list)
     emitted: int = 0
     result: Optional[ServeResult] = None
@@ -383,6 +393,7 @@ class Server:
         # (requests without model routing never create a slice)
         self._per_model: Dict[str, dict] = {}
         self._ttfe: List[float] = []             # submit -> first event
+        self._queue_waits: List[float] = []      # submit -> admitted
         self._started_at: Optional[float] = None
 
     def _model_id_of(self, request: Any) -> Optional[str]:
@@ -563,11 +574,14 @@ class Server:
         """True while any submitted request is not yet terminal."""
         return bool(self._live)
 
+    @telemetry.span("server.step")
     def step(self) -> None:
         """One scheduler tick: expire -> admit -> engine step -> deliver."""
         self._expire()
-        self.engine.admit()
+        with telemetry.span("engine.admit"):
+            admitted = self.engine.admit()
         sched = self.engine.sched
+        self._note_admitted([sched.slots[s] for s in admitted])
         if sched.any_active():
             # occupancy is averaged over ENGINE steps (device launches),
             # not idle server ticks — it answers "how full were the lanes
@@ -588,21 +602,22 @@ class Server:
                     self._mstats(mid)["occ_sum"] += occ
             self.engine.step()
         self._pump_events()
-        for rid, native in sched.drain_finished().items():
-            rec = self._records.get(rid)
-            if rec is None or rec.native is not native:
-                # not ours: submitted straight to the engine (possibly
-                # with a colliding rid — identity disambiguates)
-                continue
-            if rec.result is not None:
-                continue                        # already terminal
-            # engines may retire a request in a non-ok terminal state
-            # (duck-typed ``final_status``, e.g. a streaming lane the
-            # eject policy abandoned resolves as "ejected" — with the
-            # provisional read as its value)
-            status = getattr(self.engine, "final_status",
-                             lambda n: STATUS_OK)(native)
-            self._resolve(rec, status, self.engine.result_of(native))
+        with telemetry.span("server.resolve"):
+            for rid, native in sched.drain_finished().items():
+                rec = self._records.get(rid)
+                if rec is None or rec.native is not native:
+                    # not ours: submitted straight to the engine (possibly
+                    # with a colliding rid — identity disambiguates)
+                    continue
+                if rec.result is not None:
+                    continue                        # already terminal
+                # engines may retire a request in a non-ok terminal state
+                # (duck-typed ``final_status``, e.g. a streaming lane the
+                # eject policy abandoned resolves as "ejected" — with the
+                # provisional read as its value)
+                status = getattr(self.engine, "final_status",
+                                 lambda n: STATUS_OK)(native)
+                self._resolve(rec, status, self.engine.result_of(native))
 
     def run_until_idle(self, max_steps: int = 1_000_000
                        ) -> Dict[int, ServeResult]:
@@ -626,6 +641,19 @@ class Server:
                 f"{self.retain_results})")
         return rec
 
+    def _note_admitted(self, natives: List[Any]) -> None:
+        """Record the queue wait of each request first admitted this tick
+        (a preempted and re-admitted request counts once)."""
+        recs = [r for n in natives
+                if (r := self._owner_of(n)) is not None and not r.admitted]
+        if not recs:
+            return
+        now = self.clock()
+        for rec in recs:
+            rec.admitted = True
+            self._queue_waits.append(now - rec.submitted_at)
+
+    @telemetry.span("server.expire")
     def _expire(self) -> None:
         now = self.clock()
         for rec in [r for r in self._live.values()
@@ -637,6 +665,7 @@ class Server:
                 self.engine.sched.release(slot)
             self._resolve(rec, STATUS_EXPIRED, None)
 
+    @telemetry.span("server.events")
     def _pump_events(self) -> None:
         kind = self.engine.event_kind
         now = self.clock()
@@ -697,6 +726,7 @@ class Server:
         self.results.clear()
         self._latencies.clear()
         self._ttfe.clear()
+        self._queue_waits.clear()
         self._occ_sum = 0.0
         self._occ_dev_sum = None
         self.engine.steps = 0
@@ -772,6 +802,10 @@ class Server:
                         if self._ttfe else 0.0),
             ttfe_p99_s=(float(np.percentile(self._ttfe, 99))
                         if self._ttfe else 0.0),
+            queue_wait_p50_s=(float(np.percentile(self._queue_waits, 50))
+                              if self._queue_waits else 0.0),
+            queue_wait_p99_s=(float(np.percentile(self._queue_waits, 99))
+                              if self._queue_waits else 0.0),
             per_model=per_model,
         )
 

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.dist import sharding as shd
 from repro.pipeline import chunking
 from repro.pipeline.pipeline import BasecallPipeline, BasecallResult
@@ -172,6 +173,7 @@ class BasecallEngine:
     def submit(self, req: ReadRequest):
         self.sched.submit(req)
 
+    @telemetry.span("admit.read")
     def _admit_one(self, slot: int, req: ReadRequest):
         req.windows = chunking.chunk_signal(req.signal, self.pipe.chunk)
         req.frame_lengths = self.pipe.window_logit_lengths(
@@ -193,35 +195,40 @@ class BasecallEngine:
     def active_mask(self) -> np.ndarray:
         return self.sched.active_mask()
 
+    @telemetry.span("engine.step")
     def step(self):
         """Decode one window for every occupied lane in a single batch."""
-        batch = np.stack([
-            r.windows[r.cursor] if r is not None else self._zero
-            for r in self.sched.slots])
-        frames = np.asarray([
-            r.frame_lengths[r.cursor] if r is not None else 0
-            for r in self.sched.slots], np.int32)
-        batch, frames = jnp.asarray(batch), jnp.asarray(frames)
-        if self.mesh is not None:
-            # B = batch_slots * dp by construction, so dim 0 always divides
-            batch = jax.device_put(
-                batch, shd.batch_sharding(self.mesh, batch.ndim))
-            frames = jax.device_put(
-                frames, shd.batch_sharding(self.mesh, frames.ndim))
-        with self._mesh_ctx():
+        with telemetry.span("engine.assemble"):
+            batch = np.stack([
+                r.windows[r.cursor] if r is not None else self._zero
+                for r in self.sched.slots])
+            frames = np.asarray([
+                r.frame_lengths[r.cursor] if r is not None else 0
+                for r in self.sched.slots], np.int32)
+        with telemetry.span("engine.transfer"):
+            batch, frames = jnp.asarray(batch), jnp.asarray(frames)
+            if self.mesh is not None:
+                # B = batch_slots * dp by construction, so dim 0 divides
+                batch = jax.device_put(
+                    batch, shd.batch_sharding(self.mesh, batch.ndim))
+                frames = jax.device_put(
+                    frames, shd.batch_sharding(self.mesh, frames.ndim))
+        with telemetry.span("engine.dispatch"), self._mesh_ctx():
             reads, lens, _scores = self.pipe._decode_windows(self.params,
                                                              batch, frames)
-        reads, lens = np.asarray(reads), np.asarray(lens)
+        with telemetry.span("engine.readback"):
+            reads, lens = np.asarray(reads), np.asarray(lens)
         self.steps += 1
-        for slot, req in enumerate(self.sched.slots):
-            if req is None:
-                continue
-            req.reads.append(reads[slot])
-            req.lengths.append(int(lens[slot]))
-            req.cursor += 1
-            if req.cursor >= req.windows.shape[0]:
-                self._finalize(req)
-                self.sched.retire(slot, req.rid)
+        with telemetry.span("engine.retire"):
+            for slot, req in enumerate(self.sched.slots):
+                if req is None:
+                    continue
+                req.reads.append(reads[slot])
+                req.lengths.append(int(lens[slot]))
+                req.cursor += 1
+                if req.cursor >= req.windows.shape[0]:
+                    self._finalize(req)
+                    self.sched.retire(slot, req.rid)
 
     def _finalize(self, req: ReadRequest):
         if not req.reads:                      # zero-window (empty) signal

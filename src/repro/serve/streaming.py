@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import voting as voting_lib
 from repro.dist import sharding as shd
 from repro.pipeline import chunking
@@ -343,6 +344,7 @@ class StreamingSession:
         win, valid = self.buffer.next_window()
         return win, int(self.pipe.mcfg.output_frames(valid))
 
+    @telemetry.span("stream.push")
     def push_decoded(self, read, length: int,
                      score: float) -> List[ProvisionalBases]:
         """Record one window's decode; emit newly closed consensus bases."""
@@ -592,6 +594,7 @@ class StreamingBasecallEngine:
     def submit(self, lane: _StreamLane):
         self.sched.submit(lane)
 
+    @telemetry.span("admit.read")
     def _admit_one(self, slot: int, lane: _StreamLane):
         lane.session = StreamingSession(self.pipe, auto=False)
         lane.it = iter(lane.request.chunks)
@@ -648,49 +651,56 @@ class StreamingBasecallEngine:
         self.sched.retire(slot, lane.rid)
         return True
 
+    @telemetry.span("engine.step")
     def step(self):
         """Pull chunks, decode every lane's next ready window in one
         batch, stream closed bases, rule on ejects, retire done lanes."""
         self.steps += 1
-        lanes = list(enumerate(self.sched.slots))
-        for _, lane in lanes:
-            if lane is not None:
-                self._pull(lane)
-        wins, frames, live = [], [], []
-        for slot, lane in lanes:
-            if lane is not None and lane.session.ready() > 0:
-                w, f = lane.session.next_window()
-                wins.append(w)
-                frames.append(f)
-                live.append(slot)
-            else:
-                wins.append(self._zero)
-                frames.append(0)
+        with telemetry.span("engine.assemble"):
+            lanes = list(enumerate(self.sched.slots))
+            for _, lane in lanes:
+                if lane is not None:
+                    self._pull(lane)
+            wins, frames, live = [], [], []
+            for slot, lane in lanes:
+                if lane is not None and lane.session.ready() > 0:
+                    w, f = lane.session.next_window()
+                    wins.append(w)
+                    frames.append(f)
+                    live.append(slot)
+                else:
+                    wins.append(self._zero)
+                    frames.append(0)
+            if live:
+                wins = np.stack(wins)
+                frames = np.asarray(frames, np.int32)
         if live:
-            batch = jnp.asarray(np.stack(wins))
-            fl = jnp.asarray(np.asarray(frames, np.int32))
-            if self.mesh is not None:
-                batch = jax.device_put(
-                    batch, shd.batch_sharding(self.mesh, batch.ndim))
-                fl = jax.device_put(
-                    fl, shd.batch_sharding(self.mesh, fl.ndim))
-            with self._mesh_ctx():
+            with telemetry.span("engine.transfer"):
+                batch, fl = jnp.asarray(wins), jnp.asarray(frames)
+                if self.mesh is not None:
+                    batch = jax.device_put(
+                        batch, shd.batch_sharding(self.mesh, batch.ndim))
+                    fl = jax.device_put(
+                        fl, shd.batch_sharding(self.mesh, fl.ndim))
+            with telemetry.span("engine.dispatch"), self._mesh_ctx():
                 reads, lens, scores = self.pipe._decode_windows(
                     self.params, batch, fl)
-            reads, lens = np.asarray(reads), np.asarray(lens)
-            scores = np.asarray(scores)
+            with telemetry.span("engine.readback"):
+                reads, lens = np.asarray(reads), np.asarray(lens)
+                scores = np.asarray(scores)
+        with telemetry.span("engine.retire"):
             for slot in live:
                 lane = self.sched.slots[slot]
                 lane.session.push_decoded(reads[slot], int(lens[slot]),
                                           float(scores[slot]))
-        for slot, lane in enumerate(self.sched.slots):
-            if lane is None:
-                continue
-            if self._maybe_eject(slot, lane):
-                continue
-            if lane.session.done:
-                lane.result = lane.session.finalize()
-                self.sched.retire(slot, lane.rid)
+            for slot, lane in enumerate(self.sched.slots):
+                if lane is None:
+                    continue
+                if self._maybe_eject(slot, lane):
+                    continue
+                if lane.session.done:
+                    lane.result = lane.session.finalize()
+                    self.sched.retire(slot, lane.rid)
 
 
 __all__ = ["CONTINUE", "ACCEPT", "EJECT", "EjectPolicy", "ScoreEjectPolicy",
