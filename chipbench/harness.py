@@ -231,9 +231,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         summary = xplane.reduce(
             devices, hspans, state["trace_t1"] - state["trace_t0"],
             modules=modules)
-        summary["steps"] = sum(
-            1 for a, b in spans.t.get("engine_step", ())
-            if state["trace_t0"] <= a and b <= state["trace_t1"])
         dev["busy_s"] = summary.get("busy_s", 0.0)
         dev["window_s"] = summary["window_s"]
         peaks = load_json(HERE / "peaks.json")
@@ -266,6 +263,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     out["_run"]["vote_s"] = [round(b - a, 3) for a, b in spans.t.get(
         "vote", ()) if run["t_start"] <= a < run["t_stop"]]
     out["_run"]["setup_s"] = run["setup_s"]
+    if summary is not None:          # executions of each program traced
+        out["_run"]["traced_module_n"] = summary.get("module_n", {})
     out["_control"] = [
         dict(c, **check.compare(cfg, params, run, cell.limits, seed,
                                 control=c)["numbers"]) for c in controls]

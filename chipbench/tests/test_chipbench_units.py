@@ -9,6 +9,7 @@ import sys
 from pathlib import Path
 
 import numpy as np
+import pytest
 
 import chipbench_tiny  # noqa: F401  (puts chipbench/ on the path)
 import harness
@@ -182,22 +183,80 @@ def test_reduce_recorded_chip_trace():
     assert s["busy_s"] <= s["module_s"][name] < 1.1 * s["busy_s"]
 
 
+PEAKS = {"bf16_flops": 1e12, "int8_ops": 2e12, "f32_highest_flops": 1e11}
+
+
+def readings(windows=2560, steps=10, window_s=2.0, chips=1, module_n=None,
+             module_s=None):
+    class Rd:
+        pass
+    Rd.cfg, Rd.peaks, Rd.chips = cfg("guppy"), PEAKS, chips
+    Rd.run = {"windows": windows, "steps": steps, "window_s": window_s}
+    Rd.trace = {"module_n": module_n or {}, "module_s": module_s or {}}
+    return Rd
+
+
 def test_decode_mfu_reads_the_decode_program():
     mfu = harness.load_reader("decode_mfu.batch")
-    peaks = {"bf16_flops": 1e12, "int8_ops": 2e12, "f32_highest_flops": 1e11}
+    rd = readings(module_n={"jit_decode_windows(1)": 10.0,
+                            "jit_scan(2)": 3.0},
+                  module_s={"jit_decode_windows(1)": 0.5,
+                            "jit_scan(2)": 2.0})
+    # an execution for every step: the run's windows at peak over the
+    # module's whole device time
+    ideal = 2560 * basecaller_dnn.seconds_at_peak(rd.cfg, PEAKS)
+    assert math.isclose(mfu(rd), 100 * ideal / 0.5)
+    # a step more than the trace holds executions of: still a reading,
+    # a step's windows at peak over an execution's mean time
+    rd.run = dict(rd.run, steps=11)
+    assert math.isclose(mfu(rd), 100 * ideal / 11 / (0.5 / 10))
 
-    class Rd:
-        cfg, chips = cfg("guppy"), 1
-        run = {"windows": 2560}
-        trace = {"steps": 10, "module_n": {"jit_fn(1)": 10.0,
-                                           "jit_scan(2)": 3.0},
-                 "module_s": {"jit_fn(1)": 0.5, "jit_scan(2)": 2.0}}
 
-    Rd.peaks = peaks
-    ideal = 2560 * basecaller_dnn.seconds_at_peak(Rd.cfg, peaks)
-    assert math.isclose(mfu(Rd), 100 * ideal / 0.5)
-    Rd.trace = dict(Rd.trace, steps=11)      # no program ran every step
-    assert mfu(Rd) is None
+@pytest.mark.parametrize("traced", [9, 10, 11])
+def test_decode_mfu_reads_per_execution(traced):
+    """One execution fewer or more in the trace than the run's steps
+    reads as when they are equal: 50 ms an execution throughout."""
+    mfu = harness.load_reader("decode_mfu.batch")
+    rd = readings(module_n={"jit_decode_windows(7)": float(traced)},
+                  module_s={"jit_decode_windows(7)": 0.05 * traced})
+    per_step = 2560 / 10 * basecaller_dnn.seconds_at_peak(rd.cfg, PEAKS)
+    assert math.isclose(mfu(rd), 100 * per_step / 0.05)
+    # the same per device on four chips sharing the windows
+    rd.chips = 4
+    assert math.isclose(mfu(rd), 100 * per_step / 4 / 0.05)
+
+
+def test_decode_mfu_finds_the_decode_program_by_name():
+    mfu = harness.load_reader("decode_mfu.batch")
+    # another program that runs every step and holds more device time is
+    # not the decode program
+    rd = readings(module_n={"jit_decode_windows(3)": 10.0,
+                            "jit_other_step(4)": 10.0},
+                  module_s={"jit_decode_windows(3)": 0.5,
+                            "jit_other_step(4)": 5.0})
+    ideal = 2560 * basecaller_dnn.seconds_at_peak(rd.cfg, PEAKS)
+    assert math.isclose(mfu(rd), 100 * ideal / 0.5)
+    # nothing to read: no decode program in the trace, or no window
+    assert mfu(readings(module_n={"jit_other_step(4)": 10.0},
+                        module_s={"jit_other_step(4)": 5.0})) is None
+    rd.run = dict(rd.run, windows=0)
+    assert mfu(rd) is None
+
+
+def test_step_mfu_reads_the_window_on_the_host_clock():
+    step = harness.load_reader("step_mfu.batch")
+    rd = readings(windows=2560, window_s=2.0)
+    at_peak = basecaller_dnn.seconds_at_peak(rd.cfg, PEAKS)
+    assert math.isclose(step(rd), 100 * 2560 * at_peak / 2.0)
+    rd.chips = 4                             # four chips' seconds
+    assert math.isclose(step(rd), 100 * 2560 * at_peak / (2.0 * 4))
+    # it needs no trace, and reads below the decode program's share
+    rd.trace = None
+    assert step(rd) > 0
+    rd.trace = {"module_n": {"jit_decode_windows(1)": 10.0},
+                "module_s": {"jit_decode_windows(1)": 1.5}}
+    assert step(rd) < harness.load_reader("decode_mfu.batch")(rd)
+    assert step(readings(windows=0)) is None
 
 
 # -- the command ---------------------------------------------------------------
@@ -221,5 +280,9 @@ def test_benchmark_names_every_file():
         assert "setup_s" in [m["name"] for m in cell.end_to_end]
     for m in bench["per_layer"]:
         assert callable(harness.load_reader(m["name"]))
+    # every cell has a share of the chip's peak that no trace can drop
+    for w in bench["workloads"]:
+        assert any("mfu" in m["name"] and m["source"] == "host_clock"
+                   for m in harness.Cell(bench, w["name"]).per_layer)
     for c in bench["configs"]:
         assert (ROOT / c["file"]).exists()
