@@ -9,9 +9,13 @@ rendition is a dense equality matrix ``eq[i,j] = (r1[i] == r2[j])`` reduced
 along diagonals (``kernels/vote_cmp`` provides the Pallas tile kernel; this
 module is the algorithmic layer and pure-jnp fallback).
 
-All functions are fixed-shape and jit/vmap-safe; reads are int arrays padded
-with -1 past their length.  DNA symbols use the paper's 3-bit encoding ids
-[A,C,G,T,-] = [0,1,2,3,4] (see ``encode_3bit``).
+All jnp functions are fixed-shape and jit/vmap-safe; reads are int arrays
+padded with -1 past their length.  DNA symbols use the paper's 3-bit encoding
+ids [A,C,G,T,-] = [0,1,2,3,4] (see ``encode_3bit``).
+
+``vote_host`` is the serving path's vote: :func:`vote` bit for bit, with the
+pairwise comparisons in one fixed-shape compiled launch and the offset chain
+and majority grid in exact integer numpy, so a read's vote compiles nothing.
 """
 from __future__ import annotations
 
@@ -20,6 +24,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro import telemetry
 
 # paper Fig. 19(c): A:001 C:010 T:000 G:100 -:101
 SYM2BITS = jnp.array([
@@ -158,6 +165,102 @@ def vote_batch(reads, lengths, n_symbols: int = 4, span: int | None = None):
     """(B, R, L) -> (B, span) consensus. vmap of :func:`vote`."""
     f = functools.partial(vote, n_symbols=n_symbols, span=span)
     return jax.vmap(f)(reads, lengths)
+
+
+# ---------------------------------------------------------------------------
+# serving path: one fixed-shape comparator + host chaining and grid
+# ---------------------------------------------------------------------------
+
+#: window pairs per comparator launch: a read of up to ``PAIRS + 1`` windows
+#: votes in one launch, a longer one in ``ceil((N - 1) / PAIRS)``
+PAIRS = 256
+
+
+@functools.cache
+def _comparator(max_read_len: int):
+    """``pairwise_offset`` of ``(PAIRS, max_read_len)`` window pairs, jitted
+    once per read width: the one compiled part of :func:`vote_host`."""
+    def vote_compare(r1, l1, r2, l2):
+        return pairwise_offset(r1, l1, r2, l2)[0]
+    return jax.jit(jax.vmap(vote_compare))
+
+
+def warm_comparator(max_read_len: int) -> None:
+    """Compile :func:`vote_host`'s comparator for one read width now (an
+    engine calls this at build, so no served read's vote compiles)."""
+    reads = np.full((PAIRS, max_read_len), -1, np.int32)
+    lens = np.zeros((PAIRS,), np.int32)
+    jax.block_until_ready(
+        _comparator(max_read_len)(reads, lens, reads, lens))
+
+
+def relative_offsets(reads: np.ndarray, lengths: np.ndarray) -> np.ndarray:
+    """(N-1,) offset of window k in window k-1's frame, k = 1..N-1.
+
+    A pair's offset depends only on the pair, so every pair of a read goes
+    through the comparator at once, ``PAIRS`` a launch; the last launch is
+    padded with length-0 windows whose results are dropped."""
+    N, L = reads.shape
+    n_pairs = N - 1
+    n_launch = -(-n_pairs // PAIRS)
+    rows = np.full((n_launch * PAIRS + 1, L), -1, np.int32)
+    lens = np.zeros((n_launch * PAIRS + 1,), np.int32)
+    rows[:N], lens[:N] = reads, lengths
+    cmp = _comparator(L)
+    rel = np.empty((n_launch * PAIRS,), np.int64)
+    for i in range(n_launch):
+        a, b = i * PAIRS, (i + 1) * PAIRS
+        with telemetry.span("vote.compare"):
+            rel[a:b] = np.asarray(cmp(rows[a:b], lens[a:b],
+                                      rows[a + 1:b + 1], lens[a + 1:b + 1]))
+    return rel[:n_pairs]
+
+
+def chain_offsets(rel: np.ndarray) -> np.ndarray:
+    """:func:`align_offsets`' chain ``off_k = max(off_{k-1} + rel_k, 0)``,
+    ``off_0 = 0``, in closed form: a Lindley recursion, so with
+    ``S = [0, cumsum(rel)]`` it is ``S - running_min(S)``.  (N,) int32."""
+    s = np.concatenate([np.zeros((1,), np.int64),
+                        np.cumsum(rel, dtype=np.int64)])
+    return (s - np.minimum.accumulate(s)).astype(np.int32)
+
+
+def consensus_host(reads: np.ndarray, lengths: np.ndarray,
+                   offsets: np.ndarray, span: int) -> Tuple[np.ndarray, int]:
+    """:func:`consensus_grid` plus :func:`vote`'s compaction in numpy:
+    (consensus (span,) padded -1, consensus length)."""
+    n_symbols = 4
+    col = np.arange(reads.shape[1])
+    pos = offsets[:, None].astype(np.int64) + col
+    valid = (col < lengths[:, None]) & (pos < span)
+    sym = np.clip(reads, 0, n_symbols - 1)
+    counts = np.bincount((pos * n_symbols + sym)[valid],
+                         minlength=span * n_symbols
+                         ).reshape(span, n_symbols)
+    cons = counts[counts.any(axis=1)].argmax(axis=1)   # first maximum
+    out = np.full((span,), -1, np.int32)
+    out[: cons.size] = cons
+    return out, int(cons.size)
+
+
+def vote_host(reads, lengths, span: int | None = None
+              ) -> Tuple[np.ndarray, int]:
+    """:func:`vote` bit for bit, compiling nothing once
+    :func:`warm_comparator` has run for the read width.
+
+    Args:
+      reads: (N, L) int, padded with -1.
+      lengths: (N,) int true lengths.
+      span: length of the consensus coordinate grid (default 2*L).
+
+    Returns (consensus (span,) int32 padded -1, consensus_length).
+    """
+    reads = np.asarray(reads, np.int32)
+    lengths = np.asarray(lengths, np.int32)
+    if span is None:
+        span = 2 * reads.shape[1]
+    offsets = chain_offsets(relative_offsets(reads, lengths))
+    return consensus_host(reads, lengths, offsets, span)
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import voting as voting_lib
@@ -196,12 +195,12 @@ def chunk_signal(signal: np.ndarray, cfg: ChunkConfig) -> np.ndarray:
     return out
 
 
-def stitch_reads(reads: jnp.ndarray, lengths: jnp.ndarray,
-                 span: int | None = None
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def stitch_reads(reads, lengths, span: int | None = None
+                 ) -> Tuple[np.ndarray, int]:
     """Vote per-window reads (N, L) back into one consensus read.
 
-    Thin alias over ``core.voting.vote`` so the pipeline has a single
-    stitching entry point.  Returns (consensus (span,) padded -1, length).
+    Thin alias over ``core.voting.vote_host`` (``core.voting.vote`` bit for
+    bit, with no compile per read) so the pipeline has a single stitching
+    entry point.  Returns (consensus (span,) padded -1, length).
     """
-    return voting_lib.vote(reads, lengths, span=span)
+    return voting_lib.vote_host(reads, lengths, span=span)

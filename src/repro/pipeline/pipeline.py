@@ -128,9 +128,7 @@ class BasecallResult:
             cons, clen = reads[0], int(lengths[0])
         else:
             span = span or max_read_len * reads.shape[0]
-            cons, clen = chunking.stitch_reads(
-                jnp.asarray(reads), jnp.asarray(lengths), span=span)
-            cons, clen = np.asarray(cons), int(clen)
+            cons, clen = chunking.stitch_reads(reads, lengths, span=span)
         return cls(read=cons, length=clen, window_reads=reads,
                    window_lengths=lengths)
 

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
+from repro.core import voting
 from repro.dist import sharding as shd
 from repro.pipeline import chunking
 from repro.pipeline.pipeline import BasecallPipeline, BasecallResult
@@ -110,6 +111,9 @@ class BasecallEngine:
         self._zero = np.zeros((ck.window, pipeline.mcfg.in_channels),
                               np.float32)
         self.steps = 0
+        # the vote's one compiled part, built now so no finished read
+        # compiles (``core.voting.vote_host``)
+        voting.warm_comparator(pipeline.max_read_len)
 
     @classmethod
     def from_registry(cls, registry, model_id: str,

@@ -12,7 +12,7 @@ import pytest
 
 from repro import telemetry
 from repro.core.quant import QuantConfig
-from repro.pipeline import BasecallPipeline
+from repro.pipeline import BasecallPipeline, chunking
 from repro.serve import BasecallRequest, Server
 from repro.serve.basecall_engine import BasecallEngine
 from repro.serve.scheduler import SlotScheduler
@@ -240,6 +240,29 @@ def test_engine_through_server_records_the_span_tree(tiny_pipe):
     assert s["vote"]["count"] == len(results) == 3
     n_steps = s["server.step"]["count"]
     assert all(s[n]["count"] == n_steps for n in SERVER_PHASES)
+
+
+def test_a_built_engine_votes_reads_without_compiling(tiny_pipe):
+    """The engine compiles the vote's comparator when it is built, so
+    reads of three window counts finish with no compile under ``vote``."""
+    signals = _signals()
+    counts = {chunking.n_windows(len(s), tiny_pipe.chunk) for s in signals}
+    assert len(counts) == 3 and min(counts) >= 2
+    srv = Server(BasecallEngine(tiny_pipe, batch_slots=2), max_queue=8)
+
+    def vote_sites():
+        return {k: n for k, n in telemetry.compiles_by_site().items()
+                if k[0] in ("vote", "vote.compare")}
+
+    before = vote_sites()
+    t0 = time.perf_counter()
+    futs = [srv.submit(BasecallRequest(signal=s)) for s in signals]
+    srv.run_until_idle()
+    assert all(f.result().ok for f in futs)
+    s = telemetry.summary(t0)
+    assert s["vote"]["count"] == 3 and s["vote.compare"]["count"] == 3
+    assert s["vote"]["compiles"] == 0
+    assert vote_sites() == before
 
 
 def test_results_are_equal_with_and_without_a_profiler(tiny_pipe, tmp_path):

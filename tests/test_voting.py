@@ -1,9 +1,13 @@
 """Read-voting: longest-match alignment + consensus (paper Fig. 19)."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro import telemetry
 from repro.core import voting
 
 jax.config.update("jax_platform_name", "cpu")
@@ -110,3 +114,71 @@ def test_encode_3bit_paper_codes():
     codes = np.asarray(voting.encode_3bit(jnp.asarray([0, 1, 2, 3, 4])))
     assert codes.tolist() == [[0, 0, 1], [0, 1, 0], [1, 0, 0], [0, 0, 0],
                               [1, 0, 1]]
+
+
+# -- the serving path's host vote ---------------------------------------------
+
+P = voting.PAIRS
+
+
+def _overlapping(n, seed, width=12, hop=6, err=0.05):
+    """``n`` overlapping windows of one random sequence, 5 % substituted."""
+    rng = np.random.default_rng(seed)
+    base = rng.integers(0, 4, size=hop * n + width)
+    out = []
+    for k in range(n):
+        r = base[k * hop: k * hop + width].copy()
+        hit = rng.random(width) < err
+        r[hit] = rng.integers(0, 4, size=int(hit.sum()))
+        out.append(r.tolist())
+    return out
+
+
+def _with_empty_windows():
+    reads = _overlapping(9, seed=2)
+    reads[0] = reads[4] = reads[5] = []          # first, and two in a row
+    return reads
+
+
+def _no_match():
+    # window 2 shares no base with window 1: it is appended after it
+    return [[A, C, A, C, C], [C, A, C, C, A, A], [G, T, G, G, T], [T, G, G]]
+
+
+def _clamped():
+    # window 1 matches window 0 four bases in, so its offset is -4 and
+    # the chain clamps it to 0; windows 2-3 chain on from there
+    return [[A, C, G, T], [T, T, G, T, A, C, G, T],
+            [T, A, C, G, T, G, A], [G, T, G, A, C, C]]
+
+
+VOTE_CASES = {**{f"n{n}": (lambda n=n: _overlapping(n, seed=n))
+                 for n in (2, 3, P, P + 1, P + 2, 2 * P + 5)},
+              "empty_windows": _with_empty_windows,
+              "no_match": _no_match, "clamped": _clamped}
+
+
+@pytest.mark.parametrize("case", sorted(VOTE_CASES))
+def test_vote_host_is_bitwise_vote_and_reference(case):
+    """The serving vote equals the jnp ``vote`` bit for bit and the Python
+    oracle base for base, in ceil((N-1)/PAIRS) comparator launches."""
+    reads_list = VOTE_CASES[case]()
+    n, width = len(reads_list), 12
+    reads = np.stack([np.asarray(_pad(r, width)) for r in reads_list])
+    lens = np.asarray([len(r) for r in reads_list], np.int32)
+    span = width * n
+    rel = voting.relative_offsets(reads, lens)
+    if case == "no_match":
+        assert rel[1] == lens[1]
+    if case == "clamped":
+        assert rel[0] < 0 and np.cumsum(rel).min() < 0
+    t0 = time.perf_counter()
+    got, got_len = voting.vote_host(reads, lens, span=span)
+    launches = telemetry.summary(t0)["vote.compare"]["count"]
+    assert launches == -(-(n - 1) // P)
+    want, want_len = voting.vote(jnp.asarray(reads), jnp.asarray(lens),
+                                 span=span)
+    assert got.shape == (span,) and got.dtype == np.int32
+    assert got_len == int(want_len)
+    np.testing.assert_array_equal(got, np.asarray(want))
+    assert got[:got_len].tolist() == voting.vote_reference(reads_list)
