@@ -4,7 +4,12 @@ Everything a cell is made of is found by name from ``BENCHMARK.json``:
 its configuration (``configs/<config>.json``), its traffic mix
 (``traffic/<traffic>.json``, whose ``kind`` picks the driver), its
 per-layer metrics (``metrics/<metric>.py``, one reader each) and the
-limits of its output check (``limits/<workload>.json``).
+limits of its output check (``limits/<workload>.json``).  The
+configuration's ``reference`` names its model family: the plain
+reference ``references/<reference>.py`` and the program side
+``families/<reference>.py``, which draws the weights, builds the
+pipeline and counts a window's operations, so the harness itself knows
+no model's shape.
 """
 from __future__ import annotations
 
@@ -37,6 +42,7 @@ class Cell:
         w = cells[name]
         self.name, self.chips = name, int(w["chips"])
         self.cfg = load_json(HERE / "configs" / f"{w['config']}.json")
+        family_of(self.cfg)          # refuse before any run
         self.mix = load_json(HERE / "traffic" / f"{w['traffic']}.json")
         self.limits = load_json(HERE / "limits" / f"{name}.json")
 
@@ -51,81 +57,48 @@ def seed32(seed: int) -> int:
                & 0x7FFFFFFF)
 
 
+def family_of(cfg: dict):
+    """The program side of the configuration's model family,
+    ``families/<reference>.py``, once it has said that it covers the
+    configuration (its ``validate`` raises ``ValueError`` otherwise)."""
+    name = cfg["reference"]
+    missing = [f"chipbench/{d}/{name}.py" for d in ("families", "references")
+               if not (HERE / d / f"{name}.py").exists()]
+    if missing:
+        raise ValueError(
+            f"configuration {cfg['name']!r} names the model family "
+            f"{name!r} in 'reference', which lacks {' and '.join(missing)}: "
+            "a family is chipbench/families/<name>.py (the program side: "
+            "validate, make_params, build_pipeline, macs_by_precision, "
+            "PEAK_OF, tiny) beside chipbench/references/<name>.py (the "
+            "plain side)")
+    fam = load_module("families", name)
+    fam.validate(cfg)
+    return fam
+
+
 def make_params(cfg: dict, seed: int):
-    """Float weights of the configuration, drawn on the device from the
-    seed in one jitted call (the program packs them to its 5-bit
-    artifact itself)."""
-    import jax
-    import jax.numpy as jnp
-
-    def init(key):
-        shapes = []
-        cin = cfg["in_channels"]
-        for c in cfg["conv"]:
-            shapes.append(("conv", (c["kernel"], cin, c["channels"]),
-                           c["kernel"] * cin))
-            cin = c["channels"]
-        H = cfg["rnn_hidden"]
-        g = 3 if cfg["rnn_type"] == "gru" else 4
-        for i in range(cfg["rnn_layers"]):
-            fin = cin if i == 0 else H
-            shapes.append(("rnn", (fin, g * H), fin))
-        keys = iter(jax.random.split(key, 4 * len(shapes) + 4))
-
-        def normal(shape, fan):
-            return jax.random.normal(next(keys), shape, jnp.float32) \
-                / jnp.sqrt(float(fan))
-
-        def bias(n):
-            return 0.1 * jax.random.normal(next(keys), (n,), jnp.float32)
-
-        p = {"conv": [], "rnn": [], "fc": None}
-        for kind, shape, fan in shapes:
-            if kind == "conv":
-                p["conv"].append({"w": normal(shape, fan),
-                                  "b": bias(shape[-1])})
-            else:
-                p["rnn"].append({"w": normal(shape, fan),
-                                 "u": normal((H, g * H), H),
-                                 "b": bias(g * H)})
-        p["fc"] = {"w": normal((H, cfg["n_classes"]), H),
-                   "b": bias(cfg["n_classes"])}
-        return p
-
-    return jax.jit(init)(jax.random.PRNGKey(seed32(seed)))
+    """Float weights of the configuration, drawn by its family on the
+    device from the seed."""
+    return family_of(cfg).make_params(cfg, seed)
 
 
 def build_pipeline(cfg: dict, backend: str):
     """The program's pipeline at exactly the configuration's sizes."""
-    from repro.core.quant import QuantConfig
-    from repro.models import basecaller as bc
-    from repro.pipeline import BasecallPipeline, chunking
+    return family_of(cfg).build_pipeline(cfg, backend)
 
-    q = cfg["quant"]
-    mcfg = bc.BasecallerConfig(
-        name=cfg["name"], input_len=cfg["input_len"],
-        in_channels=cfg["in_channels"],
-        conv=tuple(bc.ConvSpec(c["kernel"], c["channels"], c["stride"])
-                   for c in cfg["conv"]),
-        rnn_type=cfg["rnn_type"], rnn_layers=cfg["rnn_layers"],
-        rnn_hidden=cfg["rnn_hidden"], rnn_direction=cfg["rnn_direction"],
-        n_classes=cfg["n_classes"],
-        quant=QuantConfig(enabled=True, bits_w=q["bits_w"],
-                          bits_a=q["bits_a"], per_channel=True))
-    return BasecallPipeline(
-        mcfg, backend=backend,
-        chunk=chunking.ChunkConfig(window=cfg["input_len"], hop=cfg["hop"]),
-        beam_width=cfg["beam_width"], max_read_len=cfg["max_read_len"],
-        decode_strip=cfg["decode_strip"])
+
+def load_module(kind: str, name: str):
+    """``<kind>/<name>.py`` under the benchmark's directory."""
+    spec = importlib.util.spec_from_file_location(
+        f"{kind}_" + name.replace(".", "_"), HERE / kind / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(name: str):
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module("metrics", name).read
 
 
 def memory_peak(chips: int) -> int:

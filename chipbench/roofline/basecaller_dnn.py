@@ -1,46 +1,21 @@
-"""Operations of the basecaller DNN per window, from the config's shapes,
-split by the precision the program runs each product at.
-
-Counts multiply-accumulates as the published structure needs them, two
-operations each:
-
-* ``conv``: every conv output frame's ``kernel x Cin x Cout`` products,
-  run as a float convolution at the default precision (one bfloat16
-  pass on a TPU);
-* ``int8``: per GRU layer and frame the three gates' input products
-  ``3 F H``, and the FC head's ``H x classes``: the packed projections,
-  int8 codes on the MXU (``quant_matmul``);
-* ``f32_highest``: per GRU layer and frame the three gates' hidden
-  products ``3 H H``, float32 at ``Precision.HIGHEST`` (``gru_seq``).
-
-Activation functions, quantization and the decode are not counted.  At
-guppy's widths this is 41.7 M MACs per window.
-"""
-
-PEAK_OF = {"conv": "bf16_flops", "int8": "int8_ops",
-           "f32_highest": "f32_highest_flops"}
+"""Operations of the basecaller DNN per window, as the configuration's
+model family counts them (``families/<reference>.py``:
+``macs_by_precision``, one window's multiply-accumulates split by the
+precision the program runs each product at, and ``PEAK_OF``, the key of
+``peaks.json`` each precision runs at).  Two operations a
+multiply-accumulate."""
+import check
+import harness
 
 
 def frames(cfg: dict) -> int:
-    t = cfg["input_len"]
-    for c in cfg["conv"]:
-        t = -(-t // c["stride"])
-    return t
+    """Frames the model emits for one window (the reference's count)."""
+    return int(check.reference(cfg["reference"]).out_frames(
+        cfg, cfg["input_len"]))
 
 
 def macs_by_precision(cfg: dict) -> dict:
-    t, cin, conv = cfg["input_len"], cfg["in_channels"], 0
-    for c in cfg["conv"]:
-        t = -(-t // c["stride"])
-        conv += t * c["kernel"] * cin * c["channels"]
-        cin = c["channels"]
-    g = 3 if cfg["rnn_type"] == "gru" else 4
-    H = cfg["rnn_hidden"]
-    proj = sum(t * g * (cin if i == 0 else H) * H
-               for i in range(cfg["rnn_layers"]))
-    hidden = cfg["rnn_layers"] * t * g * H * H
-    fc = t * H * cfg["n_classes"]
-    return {"conv": conv, "int8": proj + fc, "f32_highest": hidden}
+    return harness.family_of(cfg).macs_by_precision(cfg)
 
 
 def macs_per_window(cfg: dict) -> int:
@@ -54,5 +29,6 @@ def ops_per_window(cfg: dict) -> int:
 def seconds_at_peak(cfg: dict, peaks: dict) -> float:
     """Seconds one window's products take at the chip's peak for the
     precision each runs at."""
-    return sum(2 * m / peaks[PEAK_OF[k]]
-               for k, m in macs_by_precision(cfg).items())
+    fam = harness.family_of(cfg)
+    return sum(2 * m / peaks[fam.PEAK_OF[k]]
+               for k, m in fam.macs_by_precision(cfg).items())

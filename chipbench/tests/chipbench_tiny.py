@@ -15,18 +15,16 @@ import harness  # noqa: E402
 
 
 class TinyCell:
-    """A ``harness.Cell`` look-alike at test size: a configuration and a
-    traffic mix of the benchmark, cut down, checked against the limits of
-    ``guppy.flowcell``."""
+    """A ``harness.Cell`` look-alike at test size: a configuration of the
+    benchmark cut by its family's ``tiny`` and a traffic mix cut to 8
+    lanes, checked against the limits of ``guppy.flowcell``."""
 
     def __init__(self, config: str, traffic: str, **mix_overrides):
         base = harness.HERE
         self.name = f"tiny.{config}.{traffic}"
         self.chips = 1
         real = harness.load_json(base / "configs" / f"{config}.json")
-        self.cfg = dict(real, input_len=120, hop=60,
-                        conv=[{"kernel": 11, "channels": 8, "stride": 2}],
-                        rnn_layers=2, rnn_hidden=8, max_read_len=60)
+        self.cfg = harness.family_of(real).tiny(real)
         self.mix = harness.load_json(base / "traffic" / f"{traffic}.json")
         self.mix.update(lanes_per_chip=8, read_bases={
             "law": "lognormal", "median": 200, "sigma": 0.05, "block": 8})

@@ -1,6 +1,7 @@
 """CPU tests of the benchmark's yardstick: traffic laws, operation and
 byte counts, the trace reduction, and the refusal off a TPU."""
 import collections
+import hashlib
 import json
 import math
 import os
@@ -59,6 +60,85 @@ def test_seconds_at_peak_by_precision():
     by = basecaller_dnn.macs_by_precision(c)
     want = 2 * by["conv"] / 2.0 + 2 * by["int8"] / 4.0 + 2 * by["f32_highest"]
     assert math.isclose(basecaller_dnn.seconds_at_peak(c, peaks), want)
+
+
+# -- model families -----------------------------------------------------------
+
+def leaf_digest(tree) -> str:
+    import jax
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        a = np.asarray(leaf)
+        for part in (jax.tree_util.keystr(path), str(a.shape), a.dtype.str):
+            h.update(part.encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("seed,digest", [
+    (7, "4341bd3312018457ffd9bfe353ecfdc01ebbf0f8619b5d1a77c7b776305067de"),
+    (2 ** 33 + 12345,
+     "1f632e854803f79c41c94faecdeababce3921785311706a085ac338befd9d1c0"),
+])
+def test_guppy_weights_are_drawn_as_before(seed, digest):
+    """Digests of guppy's weights on the CPU, recorded when the harness
+    itself still drew them: its family draws the same bits."""
+    assert leaf_digest(harness.make_params(cfg("guppy"), seed)) == digest
+
+
+def test_guppy_window_counts_as_before():
+    c = cfg("guppy")
+    assert harness.family_of(c).macs_by_precision(c) == {
+        "conv": 158_400, "int8": 20_808_000, "f32_highest": 20_736_000}
+    v5e = harness.load_json(harness.HERE / "peaks.json")["TPU v5 lite"]
+    assert math.isclose(basecaller_dnn.seconds_at_peak(c, v5e),
+                        1.3706206741306121e-06, rel_tol=1e-12)
+
+
+def cell_with_config(monkeypatch, **changes):
+    """``guppy.flowcell`` with its configuration changed as it is read."""
+    real = harness.load_json
+
+    def load(path):
+        d = real(path)
+        return dict(d, **changes) if Path(path).parent.name == "configs" \
+            else d
+    monkeypatch.setattr(harness, "load_json", load)
+    return harness.Cell(json.loads((ROOT / "BENCHMARK.json").read_text()),
+                        "guppy.flowcell")
+
+
+@pytest.mark.parametrize("changes,what", [
+    ({"rnn_direction": "bidi"}, "rnn_direction 'bidi'"),
+    ({"rnn_type": "lstm"}, "rnn_type 'lstm'"),
+    ({"conv": [{"kernel": 11, "channels": 96, "stride": 2,
+                "shortcut": 1}]}, "conv[0]"),
+])
+def test_conv_gru_ctc_refuses_what_its_reference_cannot_describe(
+        monkeypatch, changes, what):
+    with pytest.raises(ValueError) as e:
+        cell_with_config(monkeypatch, **changes)
+    msg = str(e.value)
+    assert what in msg
+    assert "families/<name>.py" in msg and "references/<name>.py" in msg
+
+
+def test_cell_refuses_a_config_whose_family_is_missing(monkeypatch):
+    with pytest.raises(ValueError) as e:
+        cell_with_config(monkeypatch, reference="chiron")
+    msg = str(e.value)
+    assert "chipbench/families/chiron.py" in msg
+    assert "chipbench/references/chiron.py" in msg
+
+
+def test_tiny_cut_of_guppy_is_the_one_the_tiny_runs_had():
+    real = cfg("guppy")
+    assert harness.family_of(real).tiny(real) == dict(
+        real, input_len=120, hop=60,
+        conv=[{"kernel": 11, "channels": 8, "stride": 2}],
+        rnn_layers=2, rnn_hidden=8, max_read_len=60)
+    assert chipbench_tiny.TinyCell("guppy", "flowcell").cfg \
+        == harness.family_of(real).tiny(real)
 
 
 def test_gru_seq_cost_by_hand():
@@ -284,5 +364,9 @@ def test_benchmark_names_every_file():
     for w in bench["workloads"]:
         assert any("mfu" in m["name"] and m["source"] == "host_clock"
                    for m in harness.Cell(bench, w["name"]).per_layer)
+    # every configuration names a model family with both of its files
     for c in bench["configs"]:
         assert (ROOT / c["file"]).exists()
+        family = json.loads((ROOT / c["file"]).read_text())["reference"]
+        for side in ("references", "families"):
+            assert (harness.HERE / side / f"{family}.py").exists()
